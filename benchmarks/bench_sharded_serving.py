@@ -29,20 +29,17 @@ tuning looked up at the *per-chip* batch). Three row groups:
   which is exactly why the gate lives on the end-to-end replay instead.
 
 Devices are simulated on CPU via
-``XLA_FLAGS=--xla_force_host_platform_device_count=8``. The flag must be
-set before the XLA backend initializes, so when the current process does
-not already see 8 devices (e.g. under ``benchmarks/run.py``), ``run()``
-re-executes this module as a ``--child`` subprocess with the flag set and
-collects its rows — the CI sharded-smoke job sets the flag itself and
-runs in-process.
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``, which must be set
+before the XLA backend initializes: the CI sharded-smoke job sets it in its
+environment, and ``benchmarks/run.py`` sets it for its own process before
+any benchmark touches JAX. The benchmark runs in the calling process and
+starts no other, so it never competes with its parent for a chip.
 
 ``--smoke`` (CI) runs the tiny-graph variant and gates only equivalence.
 """
 
 from __future__ import annotations
 
-import os
-import subprocess
 import sys
 from pathlib import Path
 from typing import Dict, List
@@ -58,11 +55,10 @@ DEVICE_COUNTS = (1, 2, 4, 8)
 # same-program process-to-process variance exceeds 5% on shared-CPU hosts,
 # so tighter margins would gate on scheduling luck.
 MONOTONIC_ENVELOPE = 0.90
-ROW_PREFIX = "sharded_serving,"
 
 
 # ---------------------------------------------------------------------------
-# Child-side measurement (runs with 8 simulated devices).
+# Measurement (runs with 8 simulated devices).
 # ---------------------------------------------------------------------------
 
 def _measure(smoke: bool) -> List[str]:
@@ -192,52 +188,13 @@ def _measure(smoke: bool) -> List[str]:
     return rows
 
 
-# ---------------------------------------------------------------------------
-# Parent-side harness entry point.
-# ---------------------------------------------------------------------------
-
-def _spawn_child(smoke: bool) -> List[str]:
-    """Re-exec this module with the device-count flag set before XLA can
-    initialize, and collect the child's rows from stdout."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count="
-                        f"{N_SIM_DEVICES}").strip()
-    env["PYTHONPATH"] = os.pathsep.join(
-        [str(REPO / "src"), str(REPO), env.get("PYTHONPATH", "")]).rstrip(
-        os.pathsep)
-    cmd = [sys.executable, str(Path(__file__).resolve()), "--child"]
-    if smoke:
-        cmd.append("--smoke")
-    try:
-        proc = subprocess.run(cmd, env=env, cwd=str(REPO),
-                              capture_output=True, text=True,
-                              timeout=1800)
-    except subprocess.TimeoutExpired as e:
-        err = (e.stderr or b"").decode() if isinstance(e.stderr, bytes) \
-            else (e.stderr or "")
-        raise RuntimeError(
-            f"sharded-serving child timed out after {e.timeout}s:\n"
-            f"{err[-2000:]}") from e
-    rows = [ln for ln in proc.stdout.splitlines()
-            if ln.startswith(ROW_PREFIX)]
-    if proc.returncode != 0 or not rows:
-        raise RuntimeError(
-            f"sharded-serving child failed (rc={proc.returncode}):\n"
-            f"{proc.stderr[-2000:]}")
-    return rows
-
-
 def run(smoke: bool = False) -> List[str]:
-    import jax
-    if jax.device_count() >= N_SIM_DEVICES:
-        return _measure(smoke)
-    return _spawn_child(smoke)
+    return _measure(smoke)
 
 
 if __name__ == "__main__":
     smoke = "--smoke" in sys.argv
-    out = _measure(smoke) if "--child" in sys.argv else run(smoke)
+    out = run(smoke)
     print("\n".join(out))
     # Equivalence gates every invocation; the throughput-scaling summary is
     # only enforced for the committed full-run rows (CI schema guard) —

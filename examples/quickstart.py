@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.cnn.executor import compile_plan, forward, init_params
 from repro.cnn.models import googlenet
+from repro.compile_cache import enable_compile_cache
 from repro.core import IM2COL
 from repro.core.cost_model import FPGA_LIKE
 from repro.core.dse import identify_parameters
@@ -33,6 +34,7 @@ from repro.core.mapper import evaluate_fixed_mapping, map_network
 
 
 def main() -> None:
+    enable_compile_cache()
     # Reduced spatial size so the executor runs in seconds on CPU; the cost
     # model itself prices the full-size network just as fast.
     g = googlenet(res=56, scale=0.25)

@@ -63,6 +63,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
+
 
 def build_record(g, plan, path, buckets):
     """Autotune-or-load: records are keyed by (conv signature, bucket), so
@@ -154,6 +156,7 @@ def serve_multi(args, g, plan, record, mesh) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--res", type=int, default=56)
     ap.add_argument("--scale", type=float, default=0.25)

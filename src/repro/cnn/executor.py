@@ -537,13 +537,23 @@ def _compile_plan_base(graph: Graph, plan: Optional[ExecutionPlan], *,
         return _quiet_donation(jax.jit(_run, donate_argnums=donate_argnums),
                                donate)
 
+    from jax.sharding import PartitionSpec as P
+
     from repro.distributed.sharding import (batch_input_sharding,
                                             data_shard_count, replicated)
     n_shards = data_shard_count(mesh)
     x_sharding = batch_input_sharding(mesh)
-    jitted = jax.jit(_run, in_shardings=(replicated(mesh), x_sharding),
+    # Each chip runs the whole overlay program on its own batch shard. The
+    # SPMD partitioner cannot split a Pallas kernel, so without shard_map
+    # it would gather the batch and run every kernel whole on every chip.
+    per_chip = jax.shard_map(_run, mesh=mesh,
+                             in_specs=(replicated(mesh).spec,
+                                       x_sharding.spec),
+                             out_specs=P(x_sharding.spec[0]),
+                             check_vma=False)
+    jitted = jax.jit(per_chip, in_shardings=(replicated(mesh), x_sharding),
                      donate_argnums=donate_argnums)
-
+    lower = jitted.lower
     jitted = _quiet_donation(jitted, donate)
 
     def run(params: Params, x: jax.Array) -> jax.Array:
@@ -560,6 +570,7 @@ def _compile_plan_base(graph: Graph, plan: Optional[ExecutionPlan], *,
 
     run.mesh = mesh
     run.data_shards = n_shards
+    run.lower = lower                 # AOT inspection, as on the plain jit
     return run
 
 

@@ -217,9 +217,9 @@ def apply_conv(x: jax.Array, w: jax.Array, algo: Algorithm,
         y = apply_epilogue(winograd_ref(x, w, m=algo.m, padding=padding),
                            epilogue, bias)
         return materialize(y, out_layout)
-    # K>r multi-round path has no standalone jnp ref; fall back to the
-    # Pallas implementation in interpret mode (still winograd math).
+    # K>r multi-round path has no standalone jnp ref; it runs the Pallas
+    # implementation (still winograd math).
     return conv_winograd(x, w, m=algo.m, padding=padding,
-                         dataflow=dataflow, p1=p1, p2=p2, interpret=True,
+                         dataflow=dataflow, p1=p1, p2=p2, interpret=interpret,
                          epilogue=epilogue, bias=bias,
                          out_layout=out_layout)

@@ -38,8 +38,13 @@ class TPUSpec:
     peak_flops: float = 197e12          # bf16 FLOP/s per chip
     hbm_bw: float = 819e9               # bytes/s
     ici_bw: float = 50e9                # bytes/s per link (~3 links/axis usable)
-    vmem_bytes: int = 64 * 2 ** 20      # usable VMEM working set per core
-    vmem_budget: int = 48 * 2 ** 20     # budget the DSE may claim for GEMM blocks
+    # VMEM per TensorCore: the most a kernel's scoped-VMEM limit may ask.
+    vmem_bytes: int = 128 * 2 ** 20
+    # What the DSE may claim for one GEMM kernel's blocks
+    # (dse.vmem_working_set, counted at dtype_bytes). The overlay's arrays
+    # are f32 today (ROADMAP Speed 3), at most twice that count, so every
+    # admitted block still fits vmem_bytes.
+    vmem_budget: int = 48 * 2 ** 20
     mxu: int = 128                      # MXU systolic dimension / lane count
     sublane: int = 8
     vpu_flops: float = 3.9e12           # vector unit, for Winograd transforms

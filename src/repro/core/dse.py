@@ -34,12 +34,17 @@ class HardwareChoice:
 def vmem_working_set(p1: int, p2: int, k_panel: int, spec: TPUSpec) -> int:
     """Bytes of VMEM a (p1, p2, k_panel) GEMM block claims.
 
-    Two operand panels (double-buffered) + the f32 accumulator tile. This is
-    the TPU analogue of C(P_SA1, P_SA2 | r) ≤ C_FPGA in Algorithm 1 line 4.
+    What Mosaic counts against the kernel's scoped-VMEM limit: the two
+    operand panels and the output block, each double-buffered by the Pallas
+    pipeline, plus the f32 accumulator tile. With k_panel at least the
+    128-deep panel the kernel streams, this bounds every dataflow's binding
+    of (p1, p2). This is the TPU analogue of C(P_SA1, P_SA2 | r) ≤ C_FPGA in
+    Algorithm 1 line 4.
     """
     operand = (p1 * k_panel + k_panel * p2) * spec.dtype_bytes * 2
+    out = p1 * p2 * spec.dtype_bytes * 2
     acc = p1 * p2 * 4
-    return operand + acc
+    return operand + out + acc
 
 
 def candidate_shapes(spec: TPUSpec, k_panel: int = 512,

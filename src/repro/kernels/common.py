@@ -2,11 +2,15 @@
 from __future__ import annotations
 
 import functools
-import os
-from typing import Sequence, Tuple
+import math
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.cost_model import V5E
 
 
 # Post-GEMM epilogues the Computing Unit can fuse into a kernel's output
@@ -127,12 +131,47 @@ def batchable(fn):
 
 
 def default_interpret() -> bool:
-    """Kernels run in interpret mode unless a real TPU backend is present.
-
-    This container is CPU-only; TPU v5e is the compilation *target*. The env
-    var REPRO_PALLAS_INTERPRET=0 forces compiled mode (on real hardware).
-    """
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
+    """Kernels run in interpret mode exactly where no TPU backend exists."""
     return jax.default_backend() != "tpu"
+
+
+def phase_split(x: jax.Array, s: int) -> jax.Array:
+    """(H, W, C), H and W multiples of ``s`` → (s·s, H/s, W/s, C), where
+    phase ``a·s + b`` holds ``x[a::s, b::s]``. A stride-s window read
+    becomes a unit-stride read of one phase: kernels never issue strided
+    loads, which Mosaic refuses for 8- and 16-bit data."""
+    if s == 1:
+        return x[None]
+    h, w, c = x.shape
+    x = x.reshape(h // s, s, w // s, s, c).transpose(1, 3, 0, 2, 4)
+    return x.reshape(s * s, h // s, w // s, c)
+
+
+def sublanes(dtype) -> int:
+    """Rows of one native VMEM tile: 8 for 32-bit, 16 for 16-bit and 32
+    for 8-bit elements (all 128 lanes wide)."""
+    return 32 // np.dtype(dtype).itemsize
+
+
+def vmem_bytes(shape: Sequence[int], dtype) -> int:
+    """VMEM bytes one buffer of ``shape`` occupies once its last two dims
+    are padded to the native (sublanes, 128) tile."""
+    *lead, rows, lanes = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    return (math.prod(lead) * ceil_to(rows, sublanes(dtype))
+            * ceil_to(lanes, 128) * np.dtype(dtype).itemsize)
+
+
+def compiler_params(blocks, scratch=()) -> pltpu.CompilerParams:
+    """Mosaic parameters whose scoped-VMEM limit fits the kernel.
+
+    ``blocks`` are the ``(shape, dtype)`` of every pipelined input and
+    output block, which Pallas double-buffers; ``scratch`` are the
+    ``(shape, dtype)`` of single-buffered scratch. Half as much again is
+    left for Mosaic's own temporaries (a dot's result, a transform's
+    partial sums); the limit never exceeds the chip's VMEM.
+    """
+    footprint = (2 * sum(vmem_bytes(s, d) for s, d in blocks)
+                 + sum(vmem_bytes(s, d) for s, d in scratch))
+    limit = footprint + footprint // 2 + (4 << 20)
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=int(min(limit, V5E.vmem_bytes)))

@@ -4,9 +4,9 @@ The induced GEMM is (O1·O2, K1K2·Cin) × (K1K2·Cin, Cout); the plan's
 dataflow binds (p1, p2) onto two of those dims (Eq. 9) and this wrapper
 translates that binding into the kernel's (output-row, C_out) tiling:
 the M-dim block covers ~bm GEMM rows (bo1 = bm // O2 output rows), the
-N-dim block is bn. The K panel is held entirely in VMEM by construction
-(the whole feature map is kernel-resident), so the streamed dim needs no
-tile. Accepts (H, W, Cin) or batched (B, H, W, Cin) inputs.
+N-dim block is bn. The K dim streams in Cin chunks of at most 128 lanes,
+each chunk's window band gathered in VMEM. Accepts (H, W, Cin) or batched
+(B, H, W, Cin) inputs.
 """
 from __future__ import annotations
 
@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from repro.core.cost_model import Dataflow
 from repro.kernels.common import (batchable, ceil_to, default_interpret,
-                                  pad_bias)
+                                  pad_bias, phase_split, sublanes)
 from repro.kernels.conv_im2col.conv_im2col import conv_im2col_call
 from repro.kernels.gemm.ops import dataflow_blocks, toeplitz_gemm
 from repro.kernels.layouts import materialize, restore
@@ -63,31 +63,36 @@ def conv_im2col(x: jax.Array, w: jax.Array, stride: int = 1,
     k1, k2, _, c_out = w.shape
     if padding == "SAME":
         o1, o2 = -(-h // stride), -(-w_dim // stride)
-        ph = max((o1 - 1) * stride + k1 - h, 0)
-        pw = max((o2 - 1) * stride + k2 - w_dim, 0)
-        xp = jnp.pad(x, ((ph // 2, ph - ph // 2), (pw // 2, pw - pw // 2),
-                         (0, 0)))
+        pt = max((o1 - 1) * stride + k1 - h, 0) // 2
+        pl_ = max((o2 - 1) * stride + k2 - w_dim, 0) // 2
     else:
         o1 = (h - k1) // stride + 1
         o2 = (w_dim - k2) // stride + 1
-        xp = x
+        pt = pl_ = 0
     bm, bn, _ = dataflow_blocks(dataflow, p1, p2)
-    bo1 = min(max(1, bm // o2), o1)
+    # Output width padded to whole sublane tiles, so an output-row block
+    # flattens to GEMM rows without a relayout; rows padded to whole
+    # blocks. Both paddings compute rows that are sliced off afterwards.
+    o2p = ceil_to(o2, sublanes(x.dtype))
+    bo1 = min(max(1, bm // o2p), o1)
     o1p = ceil_to(o1, bo1)
-    # Extra bottom/right rows so the last block's window slices stay in
-    # bounds (they produce rows we slice off afterwards).
-    need_r = (o1p - 1) * stride + k1
-    need_c = (o2 - 1) * stride + k2
-    xp = jnp.pad(xp, ((0, max(0, need_r - xp.shape[0])),
-                      (0, max(0, need_c - xp.shape[1])), (0, 0)))
+    # Cin streams in chunks of at most 128 lanes (the K panel).
+    cc = ceil_to(c_in, sublanes(x.dtype)) if c_in <= 128 else 128
+    c_inp = ceil_to(c_in, cc)
+    # Rows/cols every window needs, rounded up to whole stride phases.
+    need_r = (o1p + (k1 - 1) // stride) * stride
+    need_c = (o2p + (k2 - 1) // stride) * stride
+    xp = jnp.pad(x, ((pt, max(0, need_r - h - pt)),
+                     (pl_, max(0, need_c - w_dim - pl_)),
+                     (0, c_inp - c_in)))[:need_r, :need_c]
     bc = min(bn, ceil_to(c_out, 128))
     c_outp = ceil_to(c_out, bc)
-    wm = w.reshape(k1 * k2 * c_in, c_out)
-    wm = jnp.pad(wm, ((0, 0), (0, c_outp - c_out)))
-    out = conv_im2col_call(xp, wm, k1=k1, k2=k2, stride=stride,
-                           o1=o1p, o2=o2, bo1=bo1, bc=bc,
+    wm = jnp.pad(w.reshape(k1 * k2, c_in, c_out),
+                 ((0, 0), (0, c_inp - c_in), (0, c_outp - c_out)))
+    out = conv_im2col_call(phase_split(xp, stride), wm, k1=k1, k2=k2,
+                           stride=stride, o1=o1p, o2=o2p, bo1=bo1, bc=bc, cc=cc,
                            interpret=interpret, epilogue=epilogue,
                            bias=pad_bias(bias, c_out, c_outp),
                            scale=pad_bias(scale, c_out, c_outp),
                            out_scale=out_scale)
-    return materialize(out[:o1, :, :c_out], out_layout)
+    return materialize(out[:o1, :o2, :c_out], out_layout)

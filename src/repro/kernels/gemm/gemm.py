@@ -20,19 +20,15 @@ is exactly the stall-free accumulate-in-place of the paper's PE design.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import apply_epilogue
-
-try:  # TPU memory spaces; interpret mode works without a TPU present.
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from repro.kernels.common import (apply_epilogue, compiler_params,
+                                  default_interpret)
 
 
 def _gemm_kernel(a_ref, b_ref, *rest, nk: int, epilogue: str,
@@ -69,7 +65,7 @@ def _gemm_kernel(a_ref, b_ref, *rest, nk: int, epilogue: str,
 
 
 def gemm_pallas(a: jax.Array, b: jax.Array, *, bm: int, bn: int, bk: int,
-                interpret: bool = True, out_dtype=None,
+                interpret: Optional[bool] = None, out_dtype=None,
                 epilogue: str = "none",
                 bias: jax.Array = None,
                 scale: jax.Array = None,
@@ -99,8 +95,6 @@ def gemm_pallas(a: jax.Array, b: jax.Array, *, bm: int, bn: int, bk: int,
                      else jnp.float32 if quantized else a.dtype)
 
     grid = (m // bm, n // bn, nk)
-    scratch = (pltpu.VMEM((bm, bn), acc_dtype) if _VMEM is not None
-               else pl.ANY)  # pragma: no cover
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
         pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
@@ -121,13 +115,18 @@ def gemm_pallas(a: jax.Array, b: jax.Array, *, bm: int, bn: int, bk: int,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[scratch],
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
+        compiler_params=compiler_params(
+            [((bm, bk), a.dtype), ((bk, bn), b.dtype), ((bm, bn), out_dtype),
+             ((1, bn), jnp.float32), ((1, bn), jnp.float32)],
+            [((bm, bn), acc_dtype)]),
+        interpret=default_interpret() if interpret is None else interpret,
     )(*operands)
 
 
 def batched_gemm_pallas(a: jax.Array, b: jax.Array, *, bm: int, bn: int,
-                        bk: int, interpret: bool = True, out_dtype=None,
+                        bk: int, interpret: Optional[bool] = None,
+                        out_dtype=None,
                         epilogue: str = "none",
                         bias: jax.Array = None) -> jax.Array:
     """C[g] = epilogue(A[g] @ B[g] [+ bias]) — used for the (m+r-1)^2
@@ -161,8 +160,6 @@ def batched_gemm_pallas(a: jax.Array, b: jax.Array, *, bm: int, bn: int,
                                  bias_ref[0] if bias_ref is not None else None)
             o_ref[0] = acc.astype(o_ref.dtype)
 
-    scratch = (pltpu.VMEM((bm, bn), jnp.float32) if _VMEM is not None
-               else pl.ANY)  # pragma: no cover
     in_specs = [
         pl.BlockSpec((1, bm, bk), lambda gg, i, j, kk: (gg, i, kk)),
         pl.BlockSpec((1, bk, bn), lambda gg, i, j, kk: (gg, kk, j)),
@@ -178,6 +175,10 @@ def batched_gemm_pallas(a: jax.Array, b: jax.Array, *, bm: int, bn: int,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bm, bn), lambda gg, i, j, kk: (gg, i, j)),
         out_shape=jax.ShapeDtypeStruct((g, m, n), out_dtype),
-        scratch_shapes=[scratch],
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=compiler_params(
+            [((bm, bk), a.dtype), ((bk, bn), b.dtype), ((bm, bn), out_dtype),
+             ((1, bn), jnp.float32)],
+            [((bm, bn), jnp.float32)]),
+        interpret=default_interpret() if interpret is None else interpret,
     )(*operands)

@@ -7,27 +7,26 @@ coordinate, so the feature-map block is fetched once and stays VMEM-resident
 across offsets (the paper's pipelining of the two phases).
 
 Phase 2 ("Pad-and-Accumulate", Eq. 4): each intermediate patch p_{k1,k2} is
-shifted by its offset w.r.t. the center patch and Hadamard-added. The Pallas
-kernel walks the K1K2 patches with the output block resident in VMEM
-(contiguous revisits), which is the accumulation-buffer design of §3.1 —
-bank conflicts become a non-issue because the partial sums never leave VMEM.
+shifted by its offset w.r.t. the center patch and Hadamard-added. The grid
+walks (output-row block, channel block, kernel row): each step fetches the
+K2 patches of one kernel row as an element-offset block already shifted to
+that row, and adds their K2 column-shifted windows into an output block
+that stays resident in VMEM across kernel rows — the accumulation buffer of
+§3.1, whose partial sums never leave VMEM. The feature map is phase-split
+by the stride before phase 1 (``common.phase_split``), so the patches come
+out phase-split too and every shifted window is a unit-stride read.
 """
 from __future__ import annotations
 
-import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import apply_epilogue
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from repro.kernels.common import (apply_epilogue, compiler_params,
+                                  default_interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -35,7 +34,7 @@ except Exception:  # pragma: no cover
 # ---------------------------------------------------------------------------
 
 def unit_conv_gemms(x2d: jax.Array, w: jax.Array, *, bm: int, bn: int,
-                    bk: int, interpret: bool = True) -> jax.Array:
+                    bk: int, interpret: Optional[bool] = None) -> jax.Array:
     """x2d: (H1H2, Cin); w: (K1K2, Cin, Cout) → p: (K1K2, H1H2, Cout)."""
     m, k = x2d.shape
     g, k2, n = w.shape
@@ -61,8 +60,6 @@ def unit_conv_gemms(x2d: jax.Array, w: jax.Array, *, bm: int, bn: int,
         def _flush():
             o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
-    scratch = (pltpu.VMEM((bm, bn), acc_dtype) if _VMEM is not None
-               else pl.ANY)  # pragma: no cover
     return pl.pallas_call(
         kernel,
         grid=(g, m // bm, n // bn, nk),
@@ -74,8 +71,12 @@ def unit_conv_gemms(x2d: jax.Array, w: jax.Array, *, bm: int, bn: int,
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda gg, i, j, kk: (gg, i, j)),
         out_shape=jax.ShapeDtypeStruct((g, m, n), out_dtype),
-        scratch_shapes=[scratch],
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
+        compiler_params=compiler_params(
+            [((bm, bk), x2d.dtype), ((bk, bn), w.dtype),
+             ((bm, bn), out_dtype)],
+            [((bm, bn), acc_dtype)]),
+        interpret=default_interpret() if interpret is None else interpret,
     )(x2d, w)
 
 
@@ -84,29 +85,33 @@ def unit_conv_gemms(x2d: jax.Array, w: jax.Array, *, bm: int, bn: int,
 # ---------------------------------------------------------------------------
 
 def pad_accumulate(p: jax.Array, *, k1: int, k2: int, o1: int, o2: int,
-                   stride: int = 1, pad_top: int = 0, pad_left: int = 0,
-                   interpret: bool = True, epilogue: str = "none",
+                   bo1: int, stride: int = 1,
+                   interpret: Optional[bool] = None, epilogue: str = "none",
                    bias: jax.Array = None, scale: jax.Array = None,
                    out_scale: float = None) -> jax.Array:
-    """p: (K1K2, H1p, H2p, Cout) — patches already zero-padded so that the
-    (k1, k2) shift is a pure dynamic_slice; returns (O1, O2, Cout).
+    """p: (K1, K2, S·S, Hq, Wq, C) — the unit-conv patches of the padded,
+    phase-split input; returns (o1, o2, C).
 
-    Eq. 4: z[y, x] = Σ_{k1,k2} p_{k1,k2}[S·y + k1 - pt, S·x + k2 - pl],
-    realized as slice(start=(k1, k2)) on the padded patch tensor. As the
-    final kn2row stage, it owns the fused epilogue: the accumulated output
-    streams through ReLU/bias at the flush, before ever leaving VMEM.
+    Eq. 4: z[y, x] = Σ_{k1,k2} p_{k1,k2}[S·y + k1, S·x + k2] over the
+    padded map, i.e. phase ((k1 % S)·S + k2 % S) at (y + k1 // S,
+    x + k2 // S). As the final kn2row stage, it owns the fused epilogue:
+    the accumulated output streams through ReLU/bias at the flush, before
+    ever leaving VMEM. Caller guarantees o1 % bo1 == 0, o2 a multiple of
+    the sublane tile, and C a multiple of 128 or at most 128.
 
     Int8 path: ``p`` holds exact int32 unit-conv partials; accumulation
     stays int32 and the flush dequantizes with ``scale`` ((1, C) per-
     output-channel), then bias/relu, then the optional ``out_scale``
     requantize — the whole chain in one VMEM-resident pass.
     """
-    g, h1p, h2p, c = p.shape
-    assert g == k1 * k2
-    span_r = (o1 - 1) * stride + 1
-    span_c = (o2 - 1) * stride + 1
-    assert h1p >= span_r + k1 - 1 and h2p >= span_c + k2 - 1, \
-        (p.shape, span_r, span_c)
+    p_k1, p_k2, phases, hq, wq, c = p.shape
+    assert (p_k1, p_k2) == (k1, k2), (p.shape, k1, k2)
+    assert phases == stride * stride, (p.shape, stride)
+    assert hq >= o1 + (k1 - 1) // stride and wq >= o2 + (k2 - 1) // stride, \
+        (p.shape, o1, o2)
+    assert o1 % bo1 == 0, (o1, bo1)
+    cb = min(c, 128)
+    assert c % cb == 0, c
     quantized = p.dtype == jnp.int32
     acc_dtype = jnp.int32 if quantized else jnp.float32
     out_dtype = (jnp.int8 if out_scale is not None
@@ -118,19 +123,20 @@ def pad_accumulate(p: jax.Array, *, k1: int, k2: int, o1: int, o2: int,
         scale_ref = rest.pop(0) if has_scale else None
         o_ref, acc_ref = rest[-2], rest[-1]
         bias_ref = rest[0] if len(rest) == 3 else None
-        gg = pl.program_id(0)
+        dk1 = pl.program_id(2)
 
-        @pl.when(gg == 0)
+        @pl.when(dk1 == 0)
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        dk1 = gg // k2
-        dk2 = gg % k2
-        patch = p_ref[0]                              # (H1p, H2p, C)
-        sl = jax.lax.dynamic_slice(patch, (dk1, dk2, 0), (span_r, span_c, c))
-        acc_ref[...] += sl[::stride, ::stride, :].astype(acc_dtype)
+        # The block already sits at this kernel row's shift; the K2
+        # column shifts are static windows of it.
+        for dk2 in range(k2):
+            acc_ref[...] += p_ref[0, dk2, dk2 % stride, :,
+                                  pl.ds(dk2 // stride, o2), :
+                                  ].astype(acc_dtype)
 
-        @pl.when(gg == g - 1)
+        @pl.when(dk1 == k1 - 1)
         def _flush():
             acc = apply_epilogue(
                 acc_ref[...], epilogue,
@@ -139,24 +145,36 @@ def pad_accumulate(p: jax.Array, *, k1: int, k2: int, o1: int, o2: int,
                 out_scale=out_scale)
             o_ref[...] = acc.astype(o_ref.dtype)
 
-    scratch = (pltpu.VMEM((o1, o2, c), acc_dtype) if _VMEM is not None
-               else pl.ANY)  # pragma: no cover
-    in_specs = [pl.BlockSpec((1, h1p, h2p, c), lambda gg: (gg, 0, 0, 0))]
+    def p_index(i, j, dk1):
+        # Kernel row dk1 reads the stride phases of row (dk1 % S), shifted
+        # down by dk1 // S rows. Lane offsets must be provably 128-aligned:
+        # literal 0 for a single channel block.
+        return (dk1, 0, (dk1 % stride) * stride, i * bo1 + dk1 // stride, 0,
+                j * cb if c > cb else 0)
+
+    in_specs = [pl.BlockSpec(
+        tuple(pl.Element(d) for d in (1, k2, stride, bo1, wq, cb)),
+        p_index)]
     operands = [p]
     if scale is not None:
         assert scale.shape == (1, c), (scale.shape, c)
-        in_specs.append(pl.BlockSpec((1, c), lambda gg: (0, 0)))
+        in_specs.append(pl.BlockSpec((1, cb), lambda i, j, dk1: (0, j)))
         operands.append(scale)
     if bias is not None:
         assert bias.shape == (1, c), (bias.shape, c)
-        in_specs.append(pl.BlockSpec((1, c), lambda gg: (0, 0)))
+        in_specs.append(pl.BlockSpec((1, cb), lambda i, j, dk1: (0, j)))
         operands.append(bias)
     return pl.pallas_call(
         kernel,
-        grid=(g,),
+        grid=(o1 // bo1, c // cb, k1),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((o1, o2, c), lambda gg: (0, 0, 0)),
+        out_specs=pl.BlockSpec((bo1, o2, cb), lambda i, j, dk1: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((o1, o2, c), out_dtype),
-        scratch_shapes=[scratch],
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((bo1, o2, cb), acc_dtype)],
+        compiler_params=compiler_params(
+            [((k2, stride, bo1, wq, cb), p.dtype),
+             ((bo1, o2, cb), out_dtype), ((1, cb), jnp.float32),
+             ((1, cb), jnp.float32)],
+            [((bo1, o2, cb), acc_dtype)]),
+        interpret=default_interpret() if interpret is None else interpret,
     )(*operands)

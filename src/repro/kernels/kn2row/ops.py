@@ -16,7 +16,7 @@ import jax.numpy as jnp
 
 from repro.core.cost_model import Dataflow
 from repro.kernels.common import (batchable, ceil_to, default_interpret,
-                                  pad_bias)
+                                  pad_bias, phase_split, sublanes)
 from repro.kernels.gemm.ops import dataflow_blocks
 from repro.kernels.kn2row.kn2row import pad_accumulate, unit_conv_gemms
 from repro.kernels.layouts import materialize, restore
@@ -57,27 +57,42 @@ def conv_kn2row(x: jax.Array, w: jax.Array, stride: int = 1,
         o2 = (w_dim - k2) // stride + 1
         pt = pl_ = 0
 
-    # Phase 1: (H*W, Cin) @ (K1K2, Cin, Cout) under the plan's block binding.
     bm, bn, bk = dataflow_blocks(dataflow, p1, p2)
-    m = h * w_dim
+    # Pad the map once for every shifted window (SAME halo, whole output
+    # row blocks of ~bm positions, whole stride phases) and phase-split it
+    # by the stride, so phase 1 emits patches that phase 2 reads with
+    # unit-stride windows. Zero rows stay zero through the bias-free unit
+    # convs.
+    o2p = ceil_to(o2, sublanes(x.dtype))
+    bo1 = min(max(1, bm // o2p), o1)
+    o1p = ceil_to(o1, bo1)
+    need_r = (o1p + (k1 - 1) // stride) * stride
+    need_c = (o2p + (k2 - 1) // stride) * stride
+    xp = jnp.pad(x, ((pt, max(0, need_r - h - pt)),
+                     (pl_, max(0, need_c - w_dim - pl_)),
+                     (0, 0)))[:need_r, :need_c]
+    xph = phase_split(xp, stride)                 # (S·S, Hq, Wq, Cin)
+
+    # Phase 1: (S·S·Hq·Wq, Cin) @ (K1K2, Cin, Cout) under the plan's block
+    # binding.
+    m = xph.size // c_in
     # int8 blocks need the (32, 128) minimum tile on real hardware.
-    bm_ = min(bm, ceil_to(m, 32 if x.dtype == jnp.int8 else 8))
+    bm_ = min(bm, ceil_to(m, sublanes(x.dtype)))
     bn_ = min(bn, ceil_to(c_out, 128))
     bk_ = min(bk, ceil_to(c_in, 128))
     mp, np_, kp = ceil_to(m, bm_), ceil_to(c_out, bn_), ceil_to(c_in, bk_)
-    x2d = jnp.pad(x.reshape(m, c_in), ((0, mp - m), (0, kp - c_in)))
+    x2d = jnp.pad(xph.reshape(m, c_in), ((0, mp - m), (0, kp - c_in)))
     wk = jnp.pad(w.reshape(k1 * k2, c_in, c_out),
                  ((0, 0), (0, kp - c_in), (0, np_ - c_out)))
     p = unit_conv_gemms(x2d, wk, bm=bm_, bn=bn_, bk=bk_,
                         interpret=interpret)          # (K1K2, mp, np_)
-    p = p[:, :m, :].reshape(k1 * k2, h, w_dim, np_)
+    p = p[:, :m, :].reshape(k1, k2, *xph.shape[:3], np_)
 
-    # Phase 2: zero-pad so every (k1,k2) shift is a plain slice, then
-    # accumulate on-chip.
-    p = jnp.pad(p, ((0, 0), (pt, k1), (pl_, k2), (0, 0)))
-    out = pad_accumulate(p, k1=k1, k2=k2, o1=o1, o2=o2, stride=stride,
-                         interpret=interpret, epilogue=epilogue,
+    # Phase 2: accumulate the shifted windows on-chip.
+    out = pad_accumulate(p, k1=k1, k2=k2, o1=o1p, o2=o2p, bo1=bo1,
+                         stride=stride, interpret=interpret,
+                         epilogue=epilogue,
                          bias=pad_bias(bias, c_out, np_),
                          scale=pad_bias(scale, c_out, np_),
                          out_scale=out_scale)
-    return materialize(out[:, :, :c_out], out_layout)
+    return materialize(out[:o1, :o2, :c_out], out_layout)

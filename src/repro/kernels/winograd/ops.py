@@ -16,13 +16,40 @@ import jax.numpy as jnp
 
 from repro.core.cost_model import Dataflow
 from repro.kernels.common import (apply_epilogue, batchable, ceil_to,
-                                  default_interpret)
+                                  default_interpret, pad_bias)
 from repro.kernels.gemm.ops import batched_gemm
 from repro.kernels.layouts import materialize, restore
 from repro.kernels.winograd.winograd import (input_transform,
-                                             input_transform_tiles, matrices,
+                                             input_transform_tiles,
                                              output_transform,
                                              transform_kernel_weights)
+
+
+def _lanes(c: int) -> int:
+    """Channel count the transform kernels take: at most 128, or whole
+    128-lane blocks."""
+    return c if c <= 128 else ceil_to(c, 128)
+
+
+def _winograd_gemm_out(v: jax.Array, w: jax.Array, m: int,
+                       dataflow: Dataflow, p1: int, p2: int,
+                       interpret: bool, epilogue: str,
+                       bias: Optional[jax.Array]) -> jax.Array:
+    """Shared tail of the pipeline: V (T², tiles_y, txp, Cin_p) → batched
+    transform-space GEMM → output transform with the fused epilogue →
+    spatial (tiles_y·m, txp·m, Cout_p)."""
+    r = w.shape[0]
+    tt, ty, txp, c_inp = v.shape
+    c_in, c_out = w.shape[2:]
+    c_outp = _lanes(c_out)
+    wp = jnp.pad(w, ((0, 0), (0, 0), (0, c_inp - c_in), (0, c_outp - c_out)))
+    u = transform_kernel_weights(wp, m, r).astype(v.dtype)  # (T², Cin, Cout)
+    mm = batched_gemm(v.reshape(tt, ty * txp, c_inp), u, dataflow=dataflow,
+                      p1=p1, p2=p2, interpret=interpret,
+                      out_dtype=v.dtype)              # (T², n_tiles, Cout)
+    return output_transform(mm.reshape(tt, ty, txp, c_outp), m=m, r=r,
+                            interpret=interpret, epilogue=epilogue,
+                            bias=pad_bias(bias, c_out, c_outp))
 
 
 def _conv_f_mr(x: jax.Array, w: jax.Array, m: int, o1: int, o2: int,
@@ -33,23 +60,17 @@ def _conv_f_mr(x: jax.Array, w: jax.Array, m: int, o1: int, o2: int,
     The epilogue fuses into the output transform — the last kernel of the
     Winograd pipeline."""
     r = w.shape[0]
-    t = m + r - 1
     h, w_dim, c_in = x.shape
     c_out = w.shape[-1]
     ty, tx = -(-o1 // m), -(-o2 // m)
     need_r, need_c = ty * m + r - 1, tx * m + r - 1
     xp = jnp.pad(x, ((pt, max(0, need_r - h - pt)),
-                     (pl_, max(0, need_c - w_dim - pl_)), (0, 0)))
+                     (pl_, max(0, need_c - w_dim - pl_)),
+                     (0, _lanes(c_in) - c_in)))
     v = input_transform(xp, m=m, r=r, tiles_y=ty, tiles_x=tx,
-                        interpret=interpret)          # (T², n_tiles, Cin)
-    u = transform_kernel_weights(w, m, r).astype(x.dtype)  # (T², Cin, Cout)
-    mm = batched_gemm(v, u, dataflow=dataflow, p1=p1, p2=p2,
-                      interpret=interpret,
-                      out_dtype=x.dtype)              # (T², n_tiles, Cout)
-    y = output_transform(mm, m=m, r=r, tiles_y=ty, tiles_x=tx,
-                         interpret=interpret, epilogue=epilogue,
-                         bias=(bias.reshape(1, c_out)
-                               if bias is not None else None))
+                        interpret=interpret)          # (T², ty, txp, Cin)
+    y = _winograd_gemm_out(v, w, m, dataflow, p1, p2, interpret, epilogue,
+                           bias)
     return y[:o1, :o2, :c_out]
 
 
@@ -61,17 +82,12 @@ def _conv_from_tiles(tiles: jax.Array, w: jax.Array, m: int, spec,
     layer's (T, T) input tiles, so the spatial re-gather is skipped and the
     pipeline is tile transform → batched GEMM → output transform."""
     r = w.shape[0]
-    c_out = w.shape[-1]
-    ty, tx = spec.tiles_y, spec.tiles_x
-    v = input_transform_tiles(tiles, m=m, r=r, tiles_y=ty, tiles_x=tx,
-                              interpret=interpret)
-    u = transform_kernel_weights(w, m, r).astype(tiles.dtype)
-    mm = batched_gemm(v, u, dataflow=dataflow, p1=p1, p2=p2,
-                      interpret=interpret, out_dtype=tiles.dtype)
-    y = output_transform(mm, m=m, r=r, tiles_y=ty, tiles_x=tx,
-                         interpret=interpret, epilogue=epilogue,
-                         bias=(bias.reshape(1, c_out)
-                               if bias is not None else None))
+    c_in, c_out = w.shape[2:]
+    tiles = jnp.pad(tiles, ((0, 0),) * 3 + ((0, _lanes(c_in) - c_in),))
+    v = input_transform_tiles(tiles, m=m, r=r, tiles_y=spec.tiles_y,
+                              tiles_x=spec.tiles_x, interpret=interpret)
+    y = _winograd_gemm_out(v, w, m, dataflow, p1, p2, interpret, epilogue,
+                           bias)
     return y[:spec.o1, :spec.o2, :c_out]
 
 

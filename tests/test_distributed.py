@@ -25,8 +25,7 @@ def fake_mesh_16x16() -> Mesh:
     """Axis-shape bookkeeping only — never touches devices (we build the
     mesh from a reshaped view of the single CPU device repeated? No: we use
     an abstract mesh substitute)."""
-    # AbstractMesh carries axis names/sizes without devices. Its signature
-    # in jax 0.4.37 takes ((name, size), ...) pairs.
+    # AbstractMesh carries axis names/sizes without devices.
     from jax.sharding import AbstractMesh
     return AbstractMesh((("data", 16), ("model", 16)))
 

@@ -52,10 +52,13 @@ def test_batched_gemm():
 
 
 # --------------------------------------------------------------- im2col
+# The last two run several Cin chunks and C_out blocks of 128 lanes, with
+# channel counts that are not multiples of 128.
 CASES = [(14, 14, 8, 16, 3, 3, 1, "SAME"), (28, 28, 4, 8, 5, 5, 1, "SAME"),
          (15, 15, 3, 8, 3, 3, 2, "SAME"), (14, 14, 8, 8, 1, 1, 1, "SAME"),
          (16, 16, 6, 10, 7, 7, 2, "SAME"), (14, 14, 8, 16, 3, 3, 1, "VALID"),
-         (10, 10, 6, 10, 1, 7, 1, "SAME")]
+         (10, 10, 6, 10, 1, 7, 1, "SAME"), (9, 9, 200, 136, 3, 3, 2, "SAME"),
+         (12, 11, 130, 260, 3, 3, 1, "VALID")]
 
 
 @pytest.mark.parametrize("case", CASES)
@@ -94,7 +97,9 @@ def test_conv_kn2row_matches_lax(case):
                                   (14, 14, 8, 16, 3, 2, "VALID"),
                                   (13, 11, 5, 7, 3, 2, "SAME"),
                                   (14, 14, 4, 8, 5, 2, "SAME"),
-                                  (12, 12, 3, 6, 7, 2, "SAME")])
+                                  (12, 12, 3, 6, 7, 2, "SAME"),
+                                  (10, 10, 130, 140, 3, 4, "SAME"),
+                                  (11, 9, 136, 12, 3, 2, "VALID")])
 def test_conv_winograd_matches_lax(case):
     h, w_, ci, co, k, m, pad = case
     x, w = rnd(h, w_, ci), rnd(k, k, ci, co)

@@ -127,18 +127,20 @@ def test_int8_gemm_matches_dequantized_reference():
                                   np.asarray(requantize(ref, 0.1)))
 
 
+@pytest.mark.parametrize("stride", [1, 2])
 @pytest.mark.parametrize("conv_fn", [conv_im2col, conv_kn2row],
                          ids=["im2col", "kn2row"])
-def test_int8_conv_kernels_match_dequantized_reference(conv_fn):
+def test_int8_conv_kernels_match_dequantized_reference(conv_fn, stride):
     kx, kw = jax.random.split(jax.random.PRNGKey(1))
     x = jax.random.normal(kx, (6, 6, 8), jnp.float32)
     w = jax.random.normal(kw, (3, 3, 8, 16), jnp.float32)
     in_scale = float(jnp.max(jnp.abs(x))) / INT8_MAX
     w_scale = weight_scales(w)
     from repro.kernels.conv_im2col.ref import conv_ref
-    ref = conv_ref(fake_quant(x, in_scale), fake_quant(w, w_scale))
+    ref = conv_ref(fake_quant(x, in_scale), fake_quant(w, w_scale),
+                   stride=stride)
     out = conv_fn(quantize(x, in_scale), w=quantize(w, w_scale),
-                  interpret=True, scale=in_scale * w_scale)
+                  stride=stride, interpret=True, scale=in_scale * w_scale)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
 
