@@ -79,6 +79,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import time
+from contextlib import nullcontext
 from typing import Callable, Deque, Dict, List, Optional, Sequence, Set
 
 import jax
@@ -89,6 +90,7 @@ from repro.core.algorithms import Algorithm, IM2COL
 from repro.core.graph import Graph
 from repro.core.mapper import ExecutionPlan
 from repro.distributed.fault import DeviceFault, FaultPlan, robust_zscore
+from repro.serving.spans import HostSpans
 
 # The four terminal request outcomes (RequestTrace.outcome). Exactly one
 # per submitted request; the engine's conservation invariant is
@@ -98,6 +100,12 @@ OUTCOME_COMPLETED = "completed"
 OUTCOME_REJECTED = "rejected_full"
 OUTCOME_SHED = "shed_deadline"
 OUTCOME_FAILED = "failed"
+
+_NO_SPAN = nullcontext()
+
+
+def _no_span(name: str):
+    return _NO_SPAN
 
 
 def batch_buckets(max_batch: int, shard: int = 1) -> List[int]:
@@ -144,7 +152,11 @@ class RequestTrace:
     submit→dispatch→done timeline; ``rejected_full`` / ``shed_deadline``
     / ``failed`` records stamp the decision time into ``t_dispatch`` /
     ``t_done`` with ``service_s == 0`` (no device work was billed to
-    them) and ``bucket`` the tick's bucket for failures, 0 otherwise."""
+    them) and ``bucket`` the tick's bucket for failures, 0 otherwise.
+    ``tick`` is the index of the tick that served or failed the request
+    (the engine's dispatch index since construction or ``reset()``), None
+    for requests no tick took: the ``tick``-th ``engine.stage`` and
+    ``engine.launch`` spans of the engine's recorder are that tick's."""
     rid: int
     t_submit: float
     t_dispatch: float
@@ -155,6 +167,7 @@ class RequestTrace:
     latency_s: float
     slo_ok: bool
     outcome: str = OUTCOME_COMPLETED
+    tick: Optional[int] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -259,6 +272,17 @@ class CNNServingEngine:
     instead of recompiling. Safe because compiled programs take params as
     call arguments (nothing model-specific is closed over); per-engine
     fault hooks wrap *outside* the cached callable.
+
+    ``spans`` (a ``serving.spans.HostSpans``) records the host phases of
+    every tick: ``engine.stage`` (packing the images into the staging
+    buffer), ``engine.launch`` (the bucket executable's call, retries
+    included: argument handling, the batch's host-to-device copy, enqueue),
+    ``engine.block`` (the host waiting on the device, completion-fault
+    replays included) and ``engine.unpack`` (the logits' device-to-host
+    copy, ``done``, the service EMA and the ``RequestTrace`` records). They
+    nest inside whatever span the caller wraps ``step()`` in; under
+    ``pipeline_depth >= 2`` a tick's block and unpack run inside a later
+    step, still in dispatch order. ``None`` (default) records nothing.
     """
 
     def __init__(self, graph: Graph, params, plan: Optional[ExecutionPlan],
@@ -284,8 +308,11 @@ class CNNServingEngine:
                  retry_backoff_s: float = 0.0,
                  degrade: Optional[DegradeConfig] = None,
                  cache=None,
-                 act_scales: Optional[Dict[int, float]] = None) -> None:
+                 act_scales: Optional[Dict[int, float]] = None,
+                 spans: Optional[HostSpans] = None) -> None:
         self.graph = graph
+        self.spans = spans
+        self._span = spans if spans is not None else _no_span
         self.mesh = mesh
         self.cache = cache
         # Per-layer precision map of the served plan (bf16 when the plan
@@ -662,18 +689,20 @@ class CNNServingEngine:
         here; ``_complete`` replays them from the pinned staging
         buffer."""
         attempt = 0
-        while True:
-            try:
-                self._fault_ctx = (tick_idx, attempt)
-                return self._runs[bucket](self.params, x[:bucket]), attempt
-            except DeviceFault:
-                if attempt >= self.max_retries:
-                    return None, attempt
-                self.retries_total += 1
-                self._backoff_sleep(attempt)
-                attempt += 1
-            finally:
-                self._fault_ctx = (None, 0)
+        with self._span("engine.launch"):
+            while True:
+                try:
+                    self._fault_ctx = (tick_idx, attempt)
+                    return (self._runs[bucket](self.params, x[:bucket]),
+                            attempt)
+                except DeviceFault:
+                    if attempt >= self.max_retries:
+                        return None, attempt
+                    self.retries_total += 1
+                    self._backoff_sleep(attempt)
+                    attempt += 1
+                finally:
+                    self._fault_ctx = (None, 0)
 
     def _fault_hook(self) -> None:
         """Per-invocation dispatch hook threaded through ``compile_plan``
@@ -753,16 +782,17 @@ class CNNServingEngine:
         smaller bucket after a larger one must not leak stale images into
         its padded tail. Rotation guarantees the buffer's previous tick
         has already retired (pipeline depth == buffer count)."""
-        idx = self._buf_cursor
-        self._buf_cursor = (idx + 1) % len(self._batch_bufs)
-        self._last_buf_index = idx
-        x = self._batch_bufs[idx]
-        for i, req in enumerate(batch):
-            x[i] = req.image
-        if self._filled[idx] > len(batch):
-            x[len(batch):self._filled[idx]] = 0
-        self._filled[idx] = len(batch)
-        return x
+        with self._span("engine.stage"):
+            idx = self._buf_cursor
+            self._buf_cursor = (idx + 1) % len(self._batch_bufs)
+            self._last_buf_index = idx
+            x = self._batch_bufs[idx]
+            for i, req in enumerate(batch):
+                x[i] = req.image
+            if self._filled[idx] > len(batch):
+                x[len(batch):self._filled[idx]] = 0
+            self._filled[idx] = len(batch)
+            return x
 
     # ------------------------------------------------------- completion
     def _reap(self) -> None:
@@ -788,33 +818,42 @@ class CNNServingEngine:
         exhaustion fails the tick cleanly (slot and buffer reclaimed,
         EMAs untouched, later in-flight ticks unaffected)."""
         t_block = time.perf_counter()
-        out = jax.block_until_ready(tick.out)
-        remaining = tick.ready_at_pc - time.perf_counter()
-        if remaining > 0:
-            time.sleep(remaining)           # emulated device still busy
-        fault = tick.fault
-        if fault is not None and not fault.at_dispatch:
-            while tick.attempt < fault.failures:
-                if tick.attempt >= self.max_retries:
-                    self._fail_tick(tick)
-                    return
-                self.retries_total += 1
-                self._backoff_sleep(tick.attempt)
-                tick.attempt += 1
-                # Replay from the pinned staging buffer — rotation
-                # guarantees it still holds exactly this tick's images —
-                # on the tick's pinned executable: a hot-swap between
-                # dispatch and this replay must not change the math.
-                x = self._batch_bufs[tick.buf_index]
-                run = tick.run if tick.run is not None \
-                    else self._runs[tick.bucket]
-                try:
-                    self._fault_ctx = (tick.tick_idx, tick.attempt)
-                    tick.out = run(self.params, x[:tick.bucket])
-                finally:
-                    self._fault_ctx = (None, 0)
-                out = jax.block_until_ready(tick.out)
-        t_ready = time.perf_counter()
+        with self._span("engine.block"):
+            out = jax.block_until_ready(tick.out)
+            remaining = tick.ready_at_pc - time.perf_counter()
+            if remaining > 0:
+                time.sleep(remaining)       # emulated device still busy
+            fault = tick.fault
+            if fault is not None and not fault.at_dispatch:
+                while tick.attempt < fault.failures:
+                    if tick.attempt >= self.max_retries:
+                        self._fail_tick(tick)
+                        return
+                    self.retries_total += 1
+                    self._backoff_sleep(tick.attempt)
+                    tick.attempt += 1
+                    # Replay from the pinned staging buffer — rotation
+                    # guarantees it still holds exactly this tick's images
+                    # — on the tick's pinned executable: a hot-swap between
+                    # dispatch and this replay must not change the math.
+                    x = self._batch_bufs[tick.buf_index]
+                    run = tick.run if tick.run is not None \
+                        else self._runs[tick.bucket]
+                    try:
+                        self._fault_ctx = (tick.tick_idx, tick.attempt)
+                        tick.out = run(self.params, x[:tick.bucket])
+                    finally:
+                        self._fault_ctx = (None, 0)
+                    out = jax.block_until_ready(tick.out)
+            t_ready = time.perf_counter()
+        with self._span("engine.unpack"):
+            self._unpack(tick, out, t_block, t_ready)
+
+    def _unpack(self, tick: InflightTick, out, t_block: float,
+                t_ready: float) -> None:
+        """The host side of a tick's completion once its result is ready:
+        service and overlap accounting, the logits' copy to the host into
+        ``done``, and the ``RequestTrace`` records."""
         # Serial-device occupancy: this tick could only start once the
         # previous one finished, so its service time is completion minus
         # max(launch, previous completion) — under pipelining the naive
@@ -863,7 +902,7 @@ class CNNServingEngine:
                 rid=req.rid, t_submit=req.t_submit,
                 t_dispatch=tick.t_dispatch, t_done=t_done,
                 bucket=tick.bucket, queue_s=queue_s, service_s=service,
-                latency_s=latency_s, slo_ok=slo_ok))
+                latency_s=latency_s, slo_ok=slo_ok, tick=tick.tick_idx))
         self.last_tick = {"bucket": tick.bucket, "served": len(tick.reqs),
                           "wall_s": service, "now": tick.t_dispatch,
                           "per_chip_batch": tick.bucket // self.data_shards}
@@ -910,7 +949,8 @@ class CNNServingEngine:
                 rid=req.rid, t_submit=req.t_submit,
                 t_dispatch=tick.t_dispatch, t_done=t_done,
                 bucket=tick.bucket, queue_s=queue_s, service_s=0.0,
-                latency_s=queue_s, slo_ok=False, outcome=OUTCOME_FAILED))
+                latency_s=queue_s, slo_ok=False, outcome=OUTCOME_FAILED,
+                tick=tick.tick_idx))
         self.failed_total += len(tick.reqs)
         self.last_tick = {"bucket": tick.bucket, "served": 0,
                           "wall_s": wall, "now": tick.t_dispatch,
