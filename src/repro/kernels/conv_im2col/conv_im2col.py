@@ -14,6 +14,11 @@ tiled on (output-rows × C_out), the (P_SA1, P_SA2) binding of the NS
 dataflow. The input arrives phase-split by the stride
 (``common.phase_split``), so every window is a unit-stride ``pl.ds`` read
 of one phase.
+
+A stem — few input channels, few taps — would fill a lane tile with a
+few channels per tap; ``conv_stem_call`` takes it instead: the image
+arrives channel-major, is folded by the stride into channels in VMEM, and
+every tap of its contraction packs into one dense K panel.
 """
 from __future__ import annotations
 
@@ -25,6 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.cost_model import V5E
 from repro.kernels.common import (apply_epilogue, compiler_params,
                                   default_interpret)
 
@@ -137,4 +143,142 @@ def conv_im2col_call(x: jax.Array, w: jax.Array, *, k1: int, k2: int,
              ((1, bc), jnp.float32)],
             [((m_blk, bc), acc_dtype), ((m_blk, taps * cc), x.dtype)]),
         interpret=default_interpret() if interpret is None else interpret,
+    )(*operands)
+
+
+def _stem_kernel(x_ref, w_ref, *rest, stride: int, kq1: int, kq2: int,
+                 rows: int, rp: int, cs: int, epilogue: str):
+    """One grid step = ``rows`` output rows of one image, every C_out lane.
+
+    The first step of an image folds it in VMEM, row-major:
+    ``u[m, i·cs + ch, j] = x[c, s·i + ph, s·(128·m + j) + pw]`` for every
+    folded channel ch = (ph, pw, c), made with transposes and sublane-
+    strided reads and writes (TPU memory strides sublanes, never lanes).
+    Every step then reads the cs folded channels of a folded row as whole
+    tiles, rotates the column taps into place, stacks all taps of its
+    rows into the transposed Toeplitz tile (K, rows·o2p) with aligned
+    stores, runs one dense GEMM against the (C_out, K) weights, and
+    transposes each output row back to (columns, C_out)."""
+    bias_ref = rest[0] if len(rest) == 6 else None
+    o_ref, s1_ref, s2_ref, u_ref, tt_ref = rest[-5:]
+    s = stride
+    c_in, hp, wp = x_ref.shape
+    n_m = u_ref.shape[0]
+    o2p = tt_ref.shape[1] // rows
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _fold():
+        for ch in range(s * s * c_in, cs):
+            for m in range(n_m):
+                u_ref[m, pl.ds(ch, rp, stride=cs), :] = jnp.zeros(
+                    (rp, 128), u_ref.dtype)
+        for c in range(c_in):
+            for pw in range(s):
+                # Column phase pw of every input row: rows to lanes, a
+                # strided read of the columns, and lanes back to rows.
+                for k in range(hp // 128):
+                    s1_ref[...] = x_ref[c, k * 128:(k + 1) * 128, :].T
+                    cols = s1_ref[pl.ds(pw, wp // s, stride=s), :]
+                    for m in range(n_m):
+                        s2_ref[m, k * 128:(k + 1) * 128, :] = \
+                            cols[m * 128:(m + 1) * 128].T
+                for ph in range(s):
+                    ch = (ph * s + pw) * c_in + c
+                    for m in range(n_m):
+                        u_ref[m, pl.ds(ch, rp, stride=cs), :] = \
+                            s2_ref[m, pl.ds(ph, rp, stride=s), :]
+
+    for r in range(rows):           # static unroll — layer constants
+        for q1 in range(kq1):
+            at_row = (i * rows + r + q1) * cs
+            band = jnp.concatenate(
+                [u_ref[m, pl.ds(at_row, cs), :] for m in range(n_m)], axis=1)
+            for q2 in range(kq2):
+                tap = band if q2 == 0 else pltpu.roll(band, n_m * 128 - q2, 1)
+                at = (q1 * kq2 + q2) * cs
+                tt_ref[at:at + cs, r * o2p:(r + 1) * o2p] = tap[:, :o2p]
+    acc = jnp.dot(w_ref[...], tt_ref[...],
+                  preferred_element_type=jnp.float32)   # (C_out, rows·o2p)
+    for r in range(rows):
+        y = apply_epilogue(acc[:, r * o2p:(r + 1) * o2p].T[:o_ref.shape[1]],
+                           epilogue,
+                           bias_ref[0] if bias_ref is not None else None)
+        o_ref[r] = y.astype(o_ref.dtype)
+
+
+def _stem_vmem(c_in: int, hp: int, wp: int, c_out: int, k: int, dtype, *,
+               stride: int, taps: int, rows: int, o2: int):
+    """The stem kernel's VMEM: its scratch, and the Mosaic parameters that
+    fit that scratch, the pipelined blocks and the GEMM's f32 result."""
+    rp, n_m = hp // stride, wp // stride // 128
+    o2p = -(-o2 // 128) * 128
+    scratch = [((wp, 128), dtype), ((n_m, hp, 128), dtype),
+               ((n_m, rp * (k // taps), 128), dtype), ((k, rows * o2p), dtype)]
+    blocks = [((c_in, hp, wp), dtype), ((c_out, k), dtype),
+              ((rows, o2, c_out), dtype), ((1, c_out), jnp.float32)]
+    return scratch, compiler_params(
+        blocks, scratch + [((c_out, rows * o2p), jnp.float32)])
+
+
+def stem_fits_vmem(c_in: int, hp: int, wp: int, c_out: int, k: int, dtype,
+                   *, stride: int, taps: int, rows: int, o2: int) -> bool:
+    """Whether the stem kernel, which holds a whole image in VMEM, fits
+    the chip's: the limit ``compiler_params`` asks for stays below it."""
+    _, params = _stem_vmem(c_in, hp, wp, c_out, k, dtype, stride=stride,
+                           taps=taps, rows=rows, o2=o2)
+    return params.vmem_limit_bytes < V5E.vmem_bytes
+
+
+def conv_stem_call(x: jax.Array, wt: jax.Array, *, stride: int, kq1: int,
+                   kq2: int, o1: int, o2: int, rows: int,
+                   interpret: Optional[bool] = None,
+                   epilogue: str = "none",
+                   bias: jax.Array = None) -> jax.Array:
+    """The dense stem: a conv whose whole contraction, its stride folded
+    into channels, fits one K panel (``ops.dense_stem``).
+
+    x: (Cin, s·Rp, s·L), the padded input channel-major (rows, then
+    columns on the lanes), with Rp and L multiples of 128, Rp ≥ o1 + kq1
+    - 1 and L ≥ o2p + kq2 - 1 where o2p = ceil(o2 / 128) · 128;
+    wt: (C_out_p, kq1·kq2·Cs), the re-packed weights in (row tap, column
+    tap, ph, pw, c) order, each tap's Cin·s² channels zero-padded to Cs, a
+    multiple of 8, and transposed → (o1, o2, C_out_p).
+
+    Caller guarantees o1 % rows == 0, o2 a multiple of 8 and C_out_p a
+    multiple of 128.
+    """
+    c_in, hp, wp = x.shape
+    c_out, k = wt.shape
+    s = stride
+    rp, lanes = hp // s, wp // s
+    o2p = -(-o2 // 128) * 128
+    cs = k // (kq1 * kq2)
+    assert hp % (128 * s) == 0 and wp % (128 * s) == 0, x.shape
+    assert cs % 8 == 0 and cs >= c_in * s * s, (cs, c_in, s)
+    assert rp >= o1 + kq1 - 1 and lanes >= o2p + kq2 - 1, (x.shape, o1, o2)
+    assert o1 % rows == 0 and c_out % 128 == 0, (o1, rows, c_out)
+    in_specs = [
+        # The whole image, fetched once for its row steps.
+        pl.BlockSpec((c_in, hp, wp), lambda i: (0, 0, 0)),
+        pl.BlockSpec((c_out, k), lambda i: (0, 0)),
+    ]
+    operands = [x, wt]
+    if bias is not None:
+        assert bias.shape == (1, c_out), (bias.shape, c_out)
+        in_specs.append(pl.BlockSpec((1, c_out), lambda i: (0, 0)))
+        operands.append(bias)
+    scratch, params = _stem_vmem(c_in, hp, wp, c_out, k, x.dtype, stride=s,
+                                 taps=kq1 * kq2, rows=rows, o2=o2)
+    return pl.pallas_call(
+        functools.partial(_stem_kernel, stride=s, kq1=kq1, kq2=kq2,
+                          rows=rows, rp=rp, cs=cs, epilogue=epilogue),
+        grid=(o1 // rows,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((rows, o2, c_out), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((o1, o2, c_out), x.dtype),
+        scratch_shapes=[pltpu.VMEM(shp, dt) for shp, dt in scratch],
+        compiler_params=params,
+        interpret=default_interpret() if interpret is None else interpret,
+        name="conv_stem_dense",
     )(*operands)

@@ -96,6 +96,14 @@ CASES = {
                                  epilogue="bias_relu",
                                  bias=jnp.zeros((64,))),
         ((8, 224, 224, 3), F32), ((7, 7, 3, 64), F32)),
+    "im2col_inception_v4_stem_3x3s2": (
+        lambda x, w: conv_im2col(x, w, stride=2, padding="VALID",
+                                 interpret=False, epilogue="relu"),
+        ((8, 299, 299, 3), F32), ((3, 3, 3, 32), F32)),
+    "im2col_inception_v4_stem_c4_3x3s2": (
+        lambda x, w: conv_im2col(x, w, stride=2, padding="VALID",
+                                 interpret=False, epilogue="relu"),
+        ((8, 147, 147, 64), F32), ((3, 3, 64, 96), F32)),
     "im2col_googlenet_3a_3x3": (
         lambda x, w: conv_im2col(x, w, interpret=False, epilogue="relu"),
         ((8, 28, 28, 96), F32), ((3, 3, 96, 128), F32)),
@@ -115,10 +123,20 @@ CASES = {
 }
 
 
+# Cases that take the dense stem route: they compile to one Pallas kernel
+# under its own name, which is how a device trace tells the stem's time
+# apart. Every other im2col case compiles the chunked kernel.
+STEM_CASES = {"im2col_googlenet_conv1_7x7s2", "im2col_inception_v4_stem_3x3s2"}
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(compile_text, case):
     fn, *args = CASES[case]
-    assert "tpu_custom_call" in compile_text(fn, *args)
+    text = compile_text(fn, *args)
+    assert "tpu_custom_call" in text
+    assert ("%conv_stem_dense" in text) == (case in STEM_CASES)
+    if case in STEM_CASES:
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
 @pytest.mark.parametrize("dataflow", list(Dataflow))
